@@ -27,9 +27,11 @@ off the power-of-two ladder — from cold, with signature coalescing on and
 off, and records what bucketing buys: ``n_executables`` (compile-cache
 entries after the drain), cold-start saturation rps for both modes, and the
 cold-vs-warm compile seconds of an AOT ``warmup()`` against a persistent
-compilation cache directory.  The CI bench-smoke gate asserts
-``n_executables_bucketed <= n_buckets < n_signatures`` and
-``warm_compile_s < cold_compile_s`` on this section.
+compilation cache directory (``null`` when ``JAX_COMPILATION_CACHE_DIR``
+fixes the cache, since a cold compile needs an empty one).  The CI
+bench-smoke gate asserts ``n_executables_bucketed <= n_buckets <
+n_signatures`` and, when measured, ``warm_compile_s < cold_compile_s`` on
+this section.
 
 ``--profile DIR`` wraps the measured phases in ``jax.profiler.trace(DIR)``
 (inspect with TensorBoard or Perfetto).
@@ -204,8 +206,14 @@ def _bucket_compile_times(slots: int):
     """Cold vs warm AOT ``warmup()`` seconds against a persistent compile
     cache: the warm engine is a fresh process stand-in (its executor cache
     is empty), so its compiles deserialize from disk instead of re-running
-    XLA."""
+    XLA.  A cold compile needs an empty cache directory, so when
+    ``JAX_COMPILATION_CACHE_DIR`` fixes the cache the pair is not measured
+    (``(None, None)``) rather than repointing the cache elsewhere."""
+    from repro.compile_cache import ENV_VAR
     from repro.serving import SDESampleEngine
+
+    if os.environ.get(ENV_VAR):
+        return None, None
 
     specs = [dict(s) for s in _bucket_specs()]
     args = {"nu": jnp.float32(0.2), "mu": jnp.float32(0.1),
@@ -218,12 +226,12 @@ def _bucket_compile_times(slots: int):
             eng = SDESampleEngine(ou_term(), jnp.ones(16, jnp.float32), cfg,
                                   args=args)
             t0 = time.perf_counter()
-            n = eng.warmup(specs)
-            return time.perf_counter() - t0, n
+            eng.warmup(specs)
+            return time.perf_counter() - t0
 
-        cold_s, n_exec = timed_warmup()
-        warm_s, _ = timed_warmup()
-    return cold_s, warm_s, n_exec
+        cold_s = timed_warmup()
+        warm_s = timed_warmup()
+    return cold_s, warm_s
 
 
 def run_bucketing(out_path: str = DEFAULT_OUT, *, slots: int = SLOTS,
@@ -235,7 +243,7 @@ def run_bucketing(out_path: str = DEFAULT_OUT, *, slots: int = SLOTS,
         rps_off, exec_off, _ = asyncio.run(_bucket_drain(False, slots))
     # Compile-cache timing LAST: enabling the persistent cache flips global
     # jax config, which must not touch the drains above.
-    cold_s, warm_s, _ = _bucket_compile_times(slots)
+    cold_s, warm_s = _bucket_compile_times(slots)
     section = {
         "slots": slots,
         "n_signatures": n_signatures,
@@ -251,7 +259,8 @@ def run_bucketing(out_path: str = DEFAULT_OUT, *, slots: int = SLOTS,
     emit(f"bench_load/bucketing/S{slots}", (1.0 / rps_on) * 1e6,
          f"exec {exec_on}/{exec_off} rps {rps_on:.1f}/{rps_off:.1f} "
          f"speedup={section['speedup_bucketed']:.2f} "
-         f"compile cold={cold_s:.2f}s warm={warm_s:.2f}s")
+         + (f"compile cold={cold_s:.2f}s warm={warm_s:.2f}s"
+            if cold_s is not None else "compile cold/warm not measured"))
     data = {"device": jax.devices()[0].platform, "records": []}
     if os.path.exists(out_path):
         with open(out_path) as f:

@@ -8,7 +8,11 @@ Prints ``name,us_per_call,derived`` CSV rows (see common.emit):
   table7_gbm       — Table 7/H.1: stiff-GBM stability separation
   fig_convergence  — Figs 7/8 + App. G: strong/backward rates on fBm RDEs
   bench_throughput — beyond-paper: batched sdeint trajectories/sec vs batch
+
+Every module runs even when an earlier one fails; the process then exits
+non-zero, naming the modules that failed.
 """
+import sys
 import time
 import traceback
 
@@ -25,6 +29,7 @@ def main() -> None:
     )
 
     t00 = time.time()
+    failed = []
     for mod in (table7_gbm, table1_ou, table2_vol, table3_kuramoto,
                 table4_sphere, fig_convergence, bench_throughput):
         name = mod.__name__.split(".")[-1]
@@ -35,8 +40,11 @@ def main() -> None:
         except Exception:  # noqa: BLE001 — keep the suite going
             print(f"{name},nan,ERROR")
             traceback.print_exc()
+            failed.append(name)
         print(f"# {name} took {time.time()-t0:.1f}s", flush=True)
     print(f"# total {time.time()-t00:.1f}s")
+    if failed:
+        sys.exit(f"benchmarks failed: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

@@ -82,13 +82,12 @@ def _infer_dtype(y0):
 
 
 def _ambient_mesh():
-    from jax.interpreters import pxla
-
-    mesh = pxla.thread_resources.env.physical_mesh
+    mesh = jax.sharding.get_abstract_mesh()
     if mesh.empty:
         raise ValueError(
             "mesh_axis given but no mesh: pass mesh=... or call inside "
-            "`with mesh:` (see repro.launch.mesh.make_production_mesh)"
+            "`with jax.set_mesh(mesh):` (see "
+            "repro.launch.mesh.make_production_mesh)"
         )
     return mesh
 
@@ -222,8 +221,8 @@ def sdeint(
     mesh, mesh_axis:
         Shard the batch over ``mesh_axis`` of ``mesh`` with ``shard_map``
         (multi-device Monte Carlo).  ``mesh`` defaults to the ambient
-        ``with mesh:`` context; the axis size must divide ``B``.  Requires
-        ``batch_keys``.
+        ``with jax.set_mesh(mesh):`` context; the axis size must divide
+        ``B``.  Requires ``batch_keys``.
 
     Returns
     -------
@@ -504,13 +503,5 @@ def _batched_fn(batched, n_batch: int, mesh, mesh_axis, n_operands: int = 1):
     spec = P(mesh_axis)
     in_specs = spec if n_operands == 1 else \
         (spec,) + (P(),) * (n_operands - 1)
-    try:  # jax <= 0.5
-        from jax.experimental.shard_map import shard_map
-
-        return shard_map(batched, mesh=mesh, in_specs=in_specs,
-                         out_specs=spec, check_rep=False)
-    except ImportError:  # pragma: no cover — jax >= 0.6 (same shim as optim.compression)
-        from jax import shard_map
-
-        return shard_map(batched, mesh=mesh, in_specs=in_specs,
-                         out_specs=spec)
+    return jax.shard_map(batched, mesh=mesh, in_specs=in_specs,
+                         out_specs=spec, check_vma=False)

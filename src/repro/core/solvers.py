@@ -32,17 +32,11 @@ from .pytree import tree_add, tree_axpy, tree_scale, tree_sub, tree_zeros_like
 from .tableaux import Tableau
 from .williamson import EES25_2N, EES27_2N, LowStorage
 
-# Fused step kernels (repro.kernels.sde_step): imported once at module level —
-# never inside the step hot loop — and guarded so a stripped install without
-# the kernels layer still runs every solver on the plain pytree path.
-try:
-    from repro.kernels.sde_step import ops as _fused_ops
-except Exception:  # pragma: no cover — kernels layer absent
-    _fused_ops = None
-try:
-    from repro.kernels.williamson2n.ops import williamson2n_update as _williamson2n_update
-except Exception:  # pragma: no cover — kernels layer absent
-    _williamson2n_update = None
+# Fused step kernels (repro.kernels.sde_step): imported once at module level,
+# never inside the step hot loop.
+from repro.kernels.sde_step import ops as _fused_ops
+from repro.kernels.williamson2n.ops import (
+    williamson2n_update as _williamson2n_update)
 
 
 def _rk_strong_orders(b, c):
@@ -169,7 +163,7 @@ class SDETerm:
         """
         if self.noise == "none" or g is None:
             return tree_scale(h, f)
-        if use_kernels and _fused_ops is not None and self.noise in (
+        if use_kernels and self.noise in (
                 "diagonal", "additive", "general"):
             kernel_noise = "diagonal" if self.noise == "additive" else self.noise
             return _fused_ops.tree_increment(f, g, dW, h, noise=kernel_noise)
@@ -217,7 +211,7 @@ class _PrediffusedTerm:
         return f, jax.tree_util.tree_map(jnp.ones_like, f)
 
     def combine(self, f, g, h, dW, use_kernels: bool = False):
-        if use_kernels and _fused_ops is not None:
+        if use_kernels:
             return _fused_ops.tree_increment(f, None, dW, h, noise="prediffused")
         return jax.tree_util.tree_map(lambda fi, wi: fi * h + wi, f, dW)
 
@@ -243,7 +237,7 @@ class ButcherSolver:
         self.name = tab.name
         self.evals_per_step = tab.stages
         self.is_reversible = tab.sym_order > tab.order  # effectively symmetric
-        self.use_kernels = bool(use_kernels) and _fused_ops is not None
+        self.use_kernels = bool(use_kernels)
         self.sde_form, self.strong_orders = _rk_strong_orders(tab.b, tab.c)
 
     def init(self, term, t0, y0, args):
@@ -340,7 +334,7 @@ class LowStorageSolver:
 
     def _update(self, a, b, delta, k, y):
         """delta' = a*delta + k ; y' = y + b*delta'  (optionally fused)."""
-        if self.use_kernels and _williamson2n_update is not None:
+        if self.use_kernels:
             # Explicit flatten/unflatten: an is_leaf-on-tuples unzip would
             # misfire on states that are themselves tuples.
             d_leaves, treedef = jax.tree_util.tree_flatten(delta)
@@ -370,7 +364,7 @@ class LowStorageSolver:
         # scalar noise stays on the plain path (its dW is a broadcast scalar).
         if noise == "additive":
             noise = "diagonal"
-        fused = (self.use_kernels and _fused_ops is not None
+        fused = (self.use_kernels
                  and noise in ("diagonal", "general", "prediffused"))
         y = state
         delta = tree_zeros_like(y)
@@ -442,7 +436,7 @@ class ReversibleHeun:
         # algebraic reversibility argument only needs combine(-h, -dW) ==
         # -combine(h, dW), which holds exactly on the fused path too (IEEE
         # negation is exact).
-        self.use_kernels = bool(use_kernels) and _fused_ops is not None
+        self.use_kernels = bool(use_kernels)
 
     def init(self, term, t0, y0, args):
         f, g = term.evals(t0, y0, args)
@@ -571,7 +565,7 @@ class Milstein:
         self.form = form
         self.sde_form = form  # the correction pins the interpretation directly
         self.name = f"Milstein-{form}"
-        self.use_kernels = bool(use_kernels) and _fused_ops is not None
+        self.use_kernels = bool(use_kernels)
 
     def init(self, term, t0, y0, args):
         noise = getattr(term, "noise", "diagonal")

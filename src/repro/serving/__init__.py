@@ -20,7 +20,7 @@
 from .async_engine import AsyncSDESampleEngine
 from .bucketing import BucketingConfig, BucketKey, bucket_key, group_key, ladder_rung
 from .engine import Engine, ServeConfig
-from .executor import TickExecutor, enable_persistent_compile_cache
+from .executor import TickExecutor
 from .faults import FakeClock, FaultConfig, FaultyExecutor, InjectedCrash, inject_faults
 from .scheduler import QueueFull, RetryPolicy, Scheduler, SlotPlan
 from .sde_engine import SampleRequest, SampleResult, SDESampleConfig, SDESampleEngine
@@ -32,7 +32,6 @@ __all__ = [
     "Scheduler",
     "SlotPlan",
     "TickExecutor",
-    "enable_persistent_compile_cache",
     "BucketingConfig",
     "BucketKey",
     "bucket_key",

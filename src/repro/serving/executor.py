@@ -5,7 +5,7 @@ The executor is the device half of the SDE serving core (the host half is
 its unit of work is a **tick stack** — a ``(n_ticks, slots)`` buffer of
 per-path PRNG keys, all ticks sharing one request signature — which it runs
 through :func:`repro.core.sdeint_ticks`: an on-device ``lax.map`` over the
-tick axis inside ONE jit'd, input-donating dispatch.  A deep queue therefore
+tick axis inside ONE jit'd dispatch.  A deep queue therefore
 costs one host round trip per signature *stack* instead of one per tick;
 ``n_dispatches`` / ``n_ticks`` counters expose the ratio (the
 ``bench_serving`` metric).
@@ -14,9 +14,9 @@ Executables are cached per ``(signature, n_ticks)`` — the engine dispatches
 only full ``ticks_per_dispatch`` stacks plus single ticks (shallow queue
 tails are served tick-by-tick rather than as fresh depths), so a serving
 loop that drains a deep queue touches at most two entries per signature
-and never recompiles on a varying tail.  Each entry donates its key-stack argument on backends that
-implement donation, so the per-dispatch key upload reuses the previous
-buffer instead of allocating a fresh one.
+and never recompiles on a varying tail.  The key stack is not donated: no
+output has its ``uint32`` shape, so XLA could not reuse its buffer on any
+backend (jax would only warn that the donation was unusable).
 
 When the executor is built with a ``mesh_axis``, every tick's ``slots`` axis
 is sharded over that device-mesh axis through ``sdeint``'s existing
@@ -37,28 +37,7 @@ from repro.core import parse_solver_spec, sdeint_ticks
 
 from .bucketing import BucketKey
 
-__all__ = ["TickExecutor", "enable_persistent_compile_cache"]
-
-
-def enable_persistent_compile_cache(path: str) -> None:
-    """Point jax's persistent compilation cache at ``path``.
-
-    Compiled executables are written to (and reloaded from) the directory, so
-    a fresh process warm-starts: the first dispatch of a known
-    ``(bucket, depth)`` pays deserialization instead of XLA compilation.
-    The size/time floors are dropped so even the small CPU-smoke executables
-    persist — serving executables are few (that is the point of bucketing)
-    and re-compiling any of them stalls a tick.
-    """
-    jax.config.update("jax_compilation_cache_dir", path)
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    # jax latches "no cache" at the first compile it ever runs (imports
-    # compile little helpers long before an engine exists), and config
-    # updates alone do not re-initialize it — reset so the new dir takes
-    # effect for every compile from here on.
-    from jax.experimental.compilation_cache import compilation_cache
-    compilation_cache.reset_cache()
+__all__ = ["TickExecutor"]
 
 
 class TickExecutor:
@@ -109,9 +88,7 @@ class TickExecutor:
         Steady-state serving re-enters the same executable every dispatch
         (no per-tick re-jit: the cache key is the signature-or-bucket plus
         the stack depth, and the scheduler canonicalises specs at submit so
-        equivalent spellings share an entry).  The key-stack argument is
-        donated where the backend implements donation, letting XLA reuse
-        the previous dispatch's buffer for each upload.
+        equivalent spellings share an entry).
         """
         cache_key = (key, n_ticks)
         if cache_key not in self._compiled:
@@ -153,11 +130,7 @@ class TickExecutor:
                         guard=self.guard, **extra,
                     )
 
-            # Donate the key stack so its device buffer is reused across
-            # dispatches.  CPU does not implement donation (jax would warn
-            # once per dispatch), so donate only where it takes effect.
-            donate = (0,) if jax.default_backend() != "cpu" else ()
-            self._compiled[cache_key] = jax.jit(stack, donate_argnums=donate)
+            self._compiled[cache_key] = jax.jit(stack)
         return self._compiled[cache_key]
 
     def has_compiled(self, key: Union[Tuple, BucketKey],

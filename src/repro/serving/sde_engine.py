@@ -14,7 +14,7 @@ Since PR 5 the engine is a thin façade over two layers (see
   cancellation, ``pending()`` introspection.  Pure Python, unit-testable
   without a device.
 * :class:`repro.serving.executor.TickExecutor` — device-side: runs a
-  same-signature *stack* of tick key-buffers through one jit'd, donated
+  same-signature *stack* of tick key-buffers through one jit'd
   on-device multi-tick loop (:func:`repro.core.sdeint_ticks`), so
   ``ticks_per_dispatch`` ticks cost ONE host round trip instead of one
   each; with ``mesh_axis`` set, each tick's slot axis additionally shards
@@ -31,8 +31,7 @@ Three properties make the slicing and the dispatch grouping safe:
   return identical bits (regression-tested);
 * compiled executables are cached per request *signature* (solver spec,
   horizon, step count, save cadence, adaptive tolerances / output grid) and
-  stack depth — steady-state serving never recompiles, and each cached
-  entry donates its key buffer on backends that support donation.
+  stack depth — steady-state serving never recompiles.
 
 Since PR 6 the engine **double-buffers** by default: jax dispatch is
 asynchronous, so right after a stack is handed to the device the engine
@@ -67,9 +66,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import parse_solver_spec, select_solver
 from .bucketing import BucketKey, BucketingConfig, bucket_key, group_key
-from .executor import TickExecutor, enable_persistent_compile_cache
+from .executor import TickExecutor
 from .scheduler import (
     STAT_FIELDS,
     QueueFull,
@@ -112,6 +112,8 @@ class SDESampleConfig:
     # Directory for jax's persistent compilation cache: compiled serving
     # executables are written to disk and reloaded by later processes, so a
     # restarted engine warm-starts instead of re-paying XLA compilation.
+    # Routed through repro.compile_cache.enable_compile_cache, so a set
+    # JAX_COMPILATION_CACHE_DIR wins over this path.
     compile_cache_dir: Optional[str] = None
     # Divergence guard (PR 9): every solve carries the in-loop blow-up check
     # (non-finite state, or |y| > guard_threshold) and delivers a per-path
@@ -167,7 +169,7 @@ class SDESampleEngine:
         self.args = args
         self.noise_shape = noise_shape
         if cfg.compile_cache_dir is not None:
-            enable_persistent_compile_cache(cfg.compile_cache_dir)
+            enable_compile_cache(cfg.compile_cache_dir)
         self._bucket_cfg = BucketingConfig(enabled=cfg.bucketing,
                                            min_steps=cfg.bucket_min_steps)
         self.scheduler = Scheduler(

@@ -231,11 +231,6 @@ def make_sde_train_step(
     else:
         from jax.sharding import NamedSharding
         from jax.sharding import PartitionSpec as P
-        try:  # jax <= 0.5
-            from jax.experimental.shard_map import shard_map
-        except ImportError:  # pragma: no cover — jax >= 0.6
-            from jax import shard_map
-
         rep = NamedSharding(mesh, P())
 
         def one_path(p, k):
@@ -245,10 +240,10 @@ def make_sde_train_step(
                 noise_shape=noise_shape, **extra,
             )
 
-        solve_tiled = shard_map(
+        solve_tiled = jax.shard_map(
             lambda pt, ks: jax.vmap(one_path)(pt, ks),
             mesh=mesh, in_specs=(P(mesh_axis), P(mesh_axis)),
-            out_specs=P(mesh_axis), check_rep=False,
+            out_specs=P(mesh_axis), check_vma=False,
         )
 
         def value_and_grad_batch(params, keys):

@@ -144,8 +144,8 @@ def test_sdeint_mesh_fanout_matches_vmap():
                                    np.asarray(r_sharded.y_final), rtol=1e-5)
         np.testing.assert_allclose(np.asarray(r_vmap.ys),
                                    np.asarray(r_sharded.ys), rtol=1e-5)
-        # ambient-mesh form: `with mesh:` supplies the mesh
-        with mesh:
+        # ambient-mesh form: `with jax.set_mesh(mesh):` supplies the mesh
+        with jax.set_mesh(mesh):
             r_ambient = sdeint(term, "ees25", 0.0, 1.0, 8, y0, None,
                                args=args, batch_keys=keys, mesh_axis="data")
         np.testing.assert_allclose(np.asarray(r_sharded.y_final),

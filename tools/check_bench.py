@@ -60,7 +60,8 @@ def check_serving(d: dict) -> None:
         assert b["n_executables_bucketed"] <= b["n_buckets"] < b["n_signatures"], b
         assert b["n_executables_unbucketed"] == b["n_signatures"], b
         assert b["saturation_rps_bucketed"] > 0 and b["saturation_rps_unbucketed"] > 0, b
-        assert b["warm_compile_s"] < b["cold_compile_s"], b
+        if b["cold_compile_s"] is not None:  # None: cache dir fixed by env
+            assert b["warm_compile_s"] < b["cold_compile_s"], b
 
 
 def check_kernels(d: dict) -> None:
