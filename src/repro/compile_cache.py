@@ -8,6 +8,12 @@ directory per process) never hits.  The rule:
   explicit ``path`` argument is ignored.
 * Otherwise: the caller's ``path`` if given, else ``<repo>/.jax_cache/``, a
   fixed directory inside the checkout (listed in ``.gitignore``).
+
+Entries are keyed with the program's metadata (op names, named scopes,
+source locations).  By default JAX strips it from the key, so a program
+that differs from a cached one only in its named scopes loads the cached
+executable, metadata and all, and a profile of it shows the other program's
+names.  The price: an edit that moves source lines compiles once more.
 """
 from __future__ import annotations
 
@@ -43,6 +49,7 @@ def enable_compile_cache(path: Optional[str] = None) -> str:
     jax.config.update("jax_compilation_cache_dir", directory)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     # jax latches "no cache" at the first compile it ever runs (imports
     # compile little helpers long before an engine exists), and config
     # updates alone do not re-initialize it — reset so the directory takes

@@ -241,6 +241,7 @@ def _saving_step(solver, term, grid: TimeGrid, args, masked, save_ts,
 # Full & recursive adjoints: scan-of-scans, optionally rematerialised.
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("sde_forward")
 def _solve_scan(solver, term, y0, grid: TimeGrid, args, save_every, remat_chunk,
                 save_at=None, dWs=None, guard=None):
     masked = not grid.is_uniform
@@ -354,6 +355,7 @@ def _solve_reversible(solver, term, y0, grid: TimeGrid, args, save_every,
     if save_at is not None:
         save_ts, eps_end, h_floor = _save_consts(grid, save_at)
 
+    @jax.named_scope("sde_forward")
     def forward(grid, y0, args, dWs):
         state0 = solver.init(term, grid.t0, y0, args)
 
@@ -416,6 +418,7 @@ def _solve_reversible(solver, term, y0, grid: TimeGrid, args, save_every,
         return SolveResult(solver.extract(state_f), ys, div), (grid, state_f,
                                                                args, dWs)
 
+    @jax.named_scope("sde_reverse")
     def run_bwd(res, ct):
         # The backward sweep streams the SAME bulk realization the forward
         # consumed (it is a residual, not recomputed): increments are read in
@@ -702,7 +705,8 @@ def solve(
         )
     needs_levy = getattr(solver, "needs_levy_area", False)
     if bulk_increments:
-        dWs = grid.levy_increments() if needs_levy else grid.increments()
+        with jax.named_scope("sde_brownian"):
+            dWs = grid.levy_increments() if needs_levy else grid.increments()
     else:
         dWs = None
     term, dWs = _maybe_prediffuse(solver, term, y0, grid, args, adjoint, dWs)

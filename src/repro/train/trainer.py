@@ -176,6 +176,11 @@ def make_sde_train_step(
     which are gathered to replicated and summed in the same order the
     single-device vmap transpose sums them.  Cross-path losses are supported
     — the loss runs on the gathered (replicated) result.
+
+    The step's parts run under five ``jax.named_scope``s — ``sde_brownian``,
+    ``sde_forward``, ``sde_reverse``, ``sde_loss``, ``sde_optimizer`` — that
+    name the compiled instructions' ``op_name`` for a profiler and change
+    nothing else (``docs/performance.md``).
     """
     from repro.core import get_solver, sdeint
     from repro.core.pytree import tree_blowup
@@ -221,7 +226,8 @@ def make_sde_train_step(
             adjoint=adjoint, save_every=save_every,
             noise_shape=noise_shape, batch_keys=keys, **extra,
         )
-        return loss_fn_result(p, r)
+        with jax.named_scope("sde_loss"):
+            return loss_fn_result(p, r)
 
     if mesh_axis is None:
         lg_fn = batch_loss if microbatches == 1 else jax.checkpoint(batch_loss)
@@ -277,7 +283,9 @@ def make_sde_train_step(
             def merged_loss(pp, fls):
                 fit, ait = iter(fls), iter(aux)
                 leaves = [next(fit) if f else next(ait) for f in is_f]
-                return loss_fn_result(pp, jax.tree_util.tree_unflatten(treedef, leaves))
+                with jax.named_scope("sde_loss"):
+                    return loss_fn_result(
+                        pp, jax.tree_util.tree_unflatten(treedef, leaves))
 
             l, (g_direct, f_bar) = jax.value_and_grad(
                 merged_loss, argnums=(0, 1))(params, floats)
@@ -290,7 +298,8 @@ def make_sde_train_step(
             return l, g
 
     def step(params, opt_state, key):
-        keys = path_keys(key, n_paths)
+        with jax.named_scope("sde_brownian"):
+            keys = path_keys(key, n_paths)
         if microbatches == 1:
             l, g = value_and_grad_batch(params, keys)
         else:
@@ -308,15 +317,17 @@ def make_sde_train_step(
             l = jnp.mean(ls)
             g = jax.tree_util.tree_map(lambda x: x / microbatches, gsum)
 
-        if not guard:
-            params, opt_state, gnorm = optimizer.update(g, opt_state, params)
-            return params, opt_state, {"loss": l, "grad_norm": gnorm}
-        bad = tree_blowup(g) | ~jnp.isfinite(l)
-        new_p, new_s, gnorm = optimizer.update(g, opt_state, params)
-        keep = lambda new, old: jnp.where(bad, old, new)  # noqa: E731
-        params, opt_state = jax.tree_util.tree_map(
-            keep, (new_p, new_s), (params, opt_state)
-        )
+        with jax.named_scope("sde_optimizer"):
+            if not guard:
+                params, opt_state, gnorm = optimizer.update(g, opt_state,
+                                                            params)
+                return params, opt_state, {"loss": l, "grad_norm": gnorm}
+            bad = tree_blowup(g) | ~jnp.isfinite(l)
+            new_p, new_s, gnorm = optimizer.update(g, opt_state, params)
+            keep = lambda new, old: jnp.where(bad, old, new)  # noqa: E731
+            params, opt_state = jax.tree_util.tree_map(
+                keep, (new_p, new_s), (params, opt_state)
+            )
         return params, opt_state, {"loss": l, "grad_norm": gnorm,
                                    "skipped": bad}
 
@@ -365,12 +376,15 @@ def make_scanned_step(step_fn: Callable, steps_per_call: int, *,
     def scanned(params, opt_state, counters, key, step0):
         def body(carry, s):
             p, o, c = carry
-            k = jax.random.fold_in(key, s)
+            with jax.named_scope("sde_brownian"):
+                k = jax.random.fold_in(key, s)
             p, o, m = (step_fn(p, o, k, s) if takes_step
                        else step_fn(p, o, k))
             sk = m.get("skipped", False) if isinstance(m, dict) else False
-            c = {"steps": c["steps"] + 1,
-                 "skipped": c["skipped"] + jnp.asarray(sk).astype(jnp.int32)}
+            with jax.named_scope("sde_optimizer"):
+                c = {"steps": c["steps"] + 1,
+                     "skipped": c["skipped"]
+                     + jnp.asarray(sk).astype(jnp.int32)}
             return (p, o, c), m
 
         (params, opt_state, counters), hist = jax.lax.scan(

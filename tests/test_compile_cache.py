@@ -12,7 +12,8 @@ from repro import compile_cache as cc
 
 _KNOBS = ("jax_compilation_cache_dir",
           "jax_persistent_cache_min_entry_size_bytes",
-          "jax_persistent_cache_min_compile_time_secs")
+          "jax_persistent_cache_min_compile_time_secs",
+          "jax_compilation_cache_include_metadata_in_key")
 
 
 @pytest.fixture
@@ -53,6 +54,29 @@ def test_explicit_path_without_env(tmp_path, monkeypatch, restore_cache_config):
     assert cc.enable_compile_cache(str(tmp_path)) == str(tmp_path)
     assert jax.config.jax_compilation_cache_dir == str(tmp_path)
     assert jax.config.jax_persistent_cache_min_entry_size_bytes == -1
+
+
+def test_a_cached_executable_keeps_its_own_named_scopes(
+        tmp_path, monkeypatch, restore_cache_config):
+    """Two programs that differ only in a named scope: the second is not
+    served the first's executable, whose metadata names the other scope."""
+    import jax.numpy as jnp
+
+    monkeypatch.delenv(cc.ENV_VAR, raising=False)
+    cc.enable_compile_cache(str(tmp_path))
+
+    def scoped(name):
+        def f(x):
+            with jax.named_scope(name):
+                return jnp.tanh(x) * 2
+        return f
+
+    x = jnp.ones(8)
+    first = jax.jit(scoped("sde_forward")).lower(x).compile().as_text()
+    second = jax.jit(scoped("sde_loss")).lower(x).compile().as_text()
+    assert "sde_forward" in first
+    assert "sde_loss" in second and "sde_forward" not in second
+    assert len(os.listdir(tmp_path)) >= 2
 
 
 def test_engine_config_routes_through_helper(tmp_path, monkeypatch,
