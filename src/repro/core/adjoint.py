@@ -346,7 +346,17 @@ def _solve_scan(solver, term, y0, grid: TimeGrid, args, save_every, remat_chunk,
 # ---------------------------------------------------------------------------
 
 def _solve_reversible(solver, term, y0, grid: TimeGrid, args, save_every,
-                      save_at=None, dWs=None, guard=None):
+                      save_at=None, dWs=None, guard=None, paths=False):
+    """One custom vjp over one path, or with ``paths`` over a batch of them.
+
+    ``paths``: ``dWs`` carries a leading path axis, ``y0`` and ``args`` are
+    shared by every path, and ``grid`` is a uniform unpadded grid without a
+    driver.  The per-path functions (step, reverse, extract, init) are
+    vmapped over the path axis *inside* the custom vjp, so each reverse
+    step's vjp is taken against the unbatched ``args`` and returns their
+    cotangent already summed over paths: the backward carries one
+    parameter-shaped sum, not one per path.
+    """
     n_steps = grid.n_steps
     n_seg, seg_len = _segment_counts(n_steps, save_every)
     masked = not grid.is_uniform
@@ -354,6 +364,20 @@ def _solve_reversible(solver, term, y0, grid: TimeGrid, args, save_every,
     needs_levy = getattr(solver, "needs_levy_area", False)
     if save_at is not None:
         save_ts, eps_end, h_floor = _save_consts(grid, save_at)
+    if paths:
+        lift = jax.vmap
+
+        def at_step(x, n):
+            return x[:, n]
+    else:
+        def lift(f):
+            return f
+
+        def at_step(x, n):
+            return x[n]
+
+    def extract_vjp(s, c):
+        return jax.vjp(solver.extract, s)[1](c)[0]
 
     @jax.named_scope("sde_forward")
     def forward(grid, y0, args, dWs):
@@ -408,15 +432,18 @@ def _solve_reversible(solver, term, y0, grid: TimeGrid, args, save_every,
         div = final[1] if guarded else None
         return state_of(final), (ys if save_every else None), div
 
+    if paths:
+        forward = jax.vmap(forward, in_axes=(None, None, None, 0))
+
     @jax.custom_vjp
     def run(grid, y0, args, dWs):
         state_f, ys, div = forward(grid, y0, args, dWs)
-        return SolveResult(solver.extract(state_f), ys, div)
+        return SolveResult(lift(solver.extract)(state_f), ys, div)
 
     def run_fwd(grid, y0, args, dWs):
         state_f, ys, div = forward(grid, y0, args, dWs)
-        return SolveResult(solver.extract(state_f), ys, div), (grid, state_f,
-                                                               args, dWs)
+        return SolveResult(lift(solver.extract)(state_f), ys, div), (
+            grid, state_f, args, dWs)
 
     @jax.named_scope("sde_reverse")
     def run_bwd(res, ct):
@@ -428,8 +455,7 @@ def _solve_reversible(solver, term, y0, grid: TimeGrid, args, save_every,
         ct_yf, ct_ys = ct.y_final, ct.ys
 
         # Inject the terminal cotangent through `extract`.
-        _, vjp_ex = jax.vjp(solver.extract, state_f)
-        (ct_state,) = vjp_ex(ct_yf)
+        ct_state = lift(extract_vjp)(state_f, ct_yf)
         ct_args = _float0_like(args)
 
         def body(carry, n):
@@ -439,12 +465,13 @@ def _solve_reversible(solver, term, y0, grid: TimeGrid, args, save_every,
                 dW = (grid.levy_increment(n) if needs_levy
                       else grid.increment(n))
             else:
-                dW = _pick_step(dWs, n)
+                dW = jax.tree_util.tree_map(lambda x: at_step(x, n), dWs)
             live = (h > 0) if masked else True
             # 1. Reconstruct the pre-step state (O(h^{m+1}) drift for EES;
             #    exact for algebraically reversible solvers).  Padding steps
             #    were no-ops forward, so they are no-ops backward.
-            prev = solver.reverse(term, state, t, h, dW, args)
+            prev = lift(lambda s, w: solver.reverse(term, s, t, h, w, args))(
+                state, dW)
             if masked:
                 prev = tree_select(live, prev, state)
             # 2. Cotangents of saved outputs produced by this step.
@@ -453,11 +480,10 @@ def _solve_reversible(solver, term, y0, grid: TimeGrid, args, save_every,
                 is_save = (n + 1) % seg_len == 0
                 idx = jnp.clip((n + 1) // seg_len - 1, 0, n_seg - 1)
                 picked = jax.tree_util.tree_map(
-                    lambda a: a[idx] * jnp.asarray(is_save, a.dtype), ct_ys
-                )
-                _, vex = jax.vjp(solver.extract, state)
-                (inc,) = vex(picked)
-                ct_state = tree_add(ct_state, inc)
+                    lambda a: at_step(a, idx) * jnp.asarray(is_save, a.dtype),
+                    ct_ys)
+                ct_state = tree_add(ct_state,
+                                    lift(extract_vjp)(state, picked))
             if save_at is not None:
                 # Forward wrote ys[j] = y_old + frac_j (y_new − y_old) at the
                 # saves covered by this step (save_mask is disjoint across
@@ -480,9 +506,12 @@ def _solve_reversible(solver, term, y0, grid: TimeGrid, args, save_every,
                 ct_state = tree_add(ct_state, inc)
                 pick_old = jax.tree_util.tree_map(
                     lambda c: pick(w_old, c), ct_ys)
-            # 3. Re-play the step under vjp for exact local cotangents.
+            # 3. Re-play the step under vjp for exact local cotangents.  With
+            #    ``paths`` the vjp is against the shared ``args``: its
+            #    transpose contracts the path axis, once per step.
             def step_fn(s, a):
-                return solver.step(term, s, t, h, dW, a)
+                return lift(lambda s1, w: solver.step(term, s1, t, h, w, a))(
+                    s, dW)
 
             _, vjp = jax.vjp(step_fn, prev, args)
             ct_prev, ct_args_inc = vjp(ct_state)
@@ -515,14 +544,17 @@ def _solve_reversible(solver, term, y0, grid: TimeGrid, args, save_every,
 
         # Back out through `init` (matters for solvers whose init evaluates
         # the vector field, e.g. Reversible Heun).
-        y0_rec = solver.extract(state0_rec)
+        y0_rec = lift(solver.extract)(state0_rec)
 
         def init_fn(y, a):
-            return solver.init(term, grid.t0, y, a)
+            return lift(lambda y1: solver.init(term, grid.t0, y1, a))(y)
 
         _, vjp0 = jax.vjp(init_fn, y0_rec, args)
         ct_y0, ct_args_inc = vjp0(ct_state0)
         ct_args = _ct_add(ct_args, ct_args_inc)
+        if paths:
+            # every path started from the one shared y0
+            ct_y0 = jax.tree_util.tree_map(lambda c: jnp.sum(c, 0), ct_y0)
         if save_at is not None:
             # Save entries no step covered (at/before t0, or past where a
             # budget-exhausted realization stopped) still hold the broadcast
@@ -725,3 +757,25 @@ def solve(
         return _solve_reversible(solver, term, y0, grid, args, save_every,
                                  save_at, dWs, guard)
     raise ValueError(f"unknown adjoint {adjoint!r}")
+
+
+def solve_reversible_paths(solver, term, y0, grid, args, dWs, *,
+                           save_every: Optional[int] = None,
+                           guard: Optional[float] = None) -> SolveResult:
+    """Reversible-adjoint solve of a batch of paths under ONE custom vjp.
+
+    The paths share ``y0``, ``args`` and the uniform, unpadded ``grid``;
+    ``dWs`` stacks each path's bulk Brownian realization (the rows of
+    :meth:`~repro.core.grid.TimeGrid.increments`) on a leading path axis.
+    Results gain that axis and are bitwise equal to ``jax.vmap`` of
+    :func:`solve` over the paths.  Gradients agree to rounding: the backward
+    sweep sums the ``args`` cotangent over paths inside every reverse step's
+    vjp, where the vmapped :func:`solve` keeps one sum per path and adds them
+    up after the sweep.
+    """
+    grid = _as_grid(grid)
+    if not grid.is_uniform or grid.is_padded:
+        raise ValueError("a batch of reversible paths needs a uniform, "
+                         "unpadded grid")
+    return _solve_reversible(solver, term, y0, grid, args, save_every,
+                             None, dWs, guard, paths=True)

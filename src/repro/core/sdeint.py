@@ -34,12 +34,24 @@ import jax
 import jax.numpy as jnp
 
 from .adaptive import integrate_adaptive
-from .adjoint import SolveResult, solve
+from .adjoint import SolveResult, solve, solve_reversible_paths
 from .brownian import brownian_path, padded_brownian_path, virtual_brownian_tree
 from .grid import TimeGrid
 from .registry import get_solver
 
 __all__ = ["sdeint", "sdeint_ticks", "path_keys"]
+
+# A batch under the reversible adjoint is solved either path by path (a vmap
+# outside the custom vjp) or as one batch (the vmap inside it).  Path by
+# path, the backward carries one running sum of the args' cotangent per path
+# and streams it from memory at every reverse step: about twice its bytes a
+# step.  As one batch, each reverse step's vjp sums over paths itself in a
+# few small matrix products, each at a fixed per-op latency.  On a TPU v5e,
+# LSDE training with a 72 MB per-path carry (4096 paths, 4,385 parameters)
+# ran 10.5 times faster as one batch, and with a 3 MB carry (1024 paths, 745
+# parameters, 168 steps) 2% slower.  Batches whose per-path carry exceeds
+# this many bytes are solved as one batch.
+_PER_PATH_CARRY_BYTES = 16 * 2**20
 
 
 def path_keys(key: jax.Array, n_paths: int) -> jax.Array:
@@ -157,7 +169,11 @@ def sdeint(
         first (gradient-stopped controller), then the chosen adjoint runs
         over the realized grid — the reversible backward sweep replays the
         same non-uniform step sequence, so step rejection never needs a
-        third register.  The one unsupported combination is adaptive
+        third register.  A fixed-grid ``"reversible"`` batch whose per-path
+        cotangent carry exceeds ``_PER_PATH_CARRY_BYTES`` is solved as one
+        batch (:func:`repro.core.adjoint.solve_reversible_paths`): the same
+        samples, gradients equal to rounding (``docs/adjoints.md``).  The
+        one unsupported combination is adaptive
         stepping with a solver that has no embedded error estimate
         (``reversible_heun`` / ``mcf-*`` / single-stage schemes) — grid
         *realization* needs ``step_with_error``; realize with an EES scheme
@@ -257,8 +273,51 @@ def sdeint(
         return one(key)
 
     n_batch = jax.tree_util.tree_leaves(batch_keys)[0].shape[0]
+    solver = get_solver(solver)
+    if (adjoint == "reversible" and bulk_increments and mesh is None
+            and mesh_axis is None
+            and not (adaptive or getattr(solver, "adaptive", False))
+            and _per_path_carry_bytes(args, n_batch) > _PER_PATH_CARRY_BYTES):
+        return _reversible_paths_fn(
+            term, solver, t0, t1, n_steps, y0, args=args,
+            save_every=save_every, guard=guard, noise_shape=noise_shape,
+            dtype=dtype)(batch_keys)
     batched = _batched_fn(jax.vmap(one), n_batch, mesh, mesh_axis)
     return batched(batch_keys)
+
+
+def _per_path_carry_bytes(args, n_paths: int) -> int:
+    """Bytes of the args' cotangents kept once per path."""
+    return n_paths * sum(
+        int(jnp.size(l)) * jnp.dtype(jnp.result_type(l)).itemsize
+        for l in jax.tree_util.tree_leaves(args)
+        if jnp.issubdtype(jnp.result_type(l), jnp.inexact))
+
+
+def _reversible_paths_fn(term, solver, t0, t1, n_steps, y0, *, args,
+                         save_every, guard, noise_shape, dtype):
+    """``keys -> result`` of a fixed-grid reversible batch solved as one
+    (:func:`~repro.core.adjoint.solve_reversible_paths`); bitwise the same
+    results as the vmapped single-trajectory fn."""
+    if noise_shape is None:
+        noise_shape = _infer_noise_shape(term, y0)
+    if dtype is None:
+        dtype = _infer_dtype(y0)
+    needs_levy = getattr(solver, "needs_levy_area", False)
+
+    def increments(k):
+        grid = TimeGrid.from_path(
+            brownian_path(k, t0, t1, n_steps, shape=noise_shape, dtype=dtype))
+        return grid.levy_increments() if needs_levy else grid.increments()
+
+    def batch(keys):
+        with jax.named_scope("sde_brownian"):
+            dWs = jax.vmap(increments)(keys)
+        return solve_reversible_paths(
+            solver, term, y0, TimeGrid.uniform(t0, t1, n_steps), args, dWs,
+            save_every=save_every, guard=guard)
+
+    return batch
 
 
 def sdeint_ticks(

@@ -170,12 +170,15 @@ def make_sde_train_step(
     ``mesh``/``mesh_axis`` shard the Monte-Carlo path axis over a device mesh
     (:func:`repro.launch.mesh.make_sample_mesh` /
     :func:`~repro.launch.mesh.make_train_mesh`) with ``shard_map``.  Loss and
-    gradients are **bitwise-identical** to the single-device step: parameters
-    are tiled per path, the sharded ``vjp`` yields *per-path* gradients (no
-    in-``shard_map`` cross-path reduction, hence no ``psum`` reassociation),
-    which are gathered to replicated and summed in the same order the
-    single-device vmap transpose sums them.  Cross-path losses are supported
-    — the loss runs on the gathered (replicated) result.
+    gradients are **bitwise-identical** to a single-device step that solves
+    its batch path by path: parameters are tiled per path, the sharded
+    ``vjp`` yields *per-path* gradients (no in-``shard_map`` cross-path
+    reduction, hence no ``psum`` reassociation), which are gathered to
+    replicated and summed in the same order the single-device vmap transpose
+    sums them.  A single-device reversible batch large enough to be solved
+    as one (:func:`~repro.core.sdeint.sdeint`) sums over paths at every
+    reverse step instead, and agrees to rounding.  Cross-path losses are
+    supported — the loss runs on the gathered (replicated) result.
 
     The step's parts run under five ``jax.named_scope``s — ``sde_brownian``,
     ``sde_forward``, ``sde_reverse``, ``sde_loss``, ``sde_optimizer`` — that

@@ -15,6 +15,9 @@ signature loss:
   scan (``iota``), and the vmap boundary of ``sdeint`` (the path axis moved
   to the front of the results and back, and the sum over paths of the
   per-path parameter cotangents);
+* a reversible batch solved as one (``reversible-paths``: the vmap inside
+  the custom vjp, as ``sdeint`` does where the per-path cotangent carry
+  would be large) has no vmap boundary, so no exception for it;
 * the full adjoint has no ``sde_reverse``: its backward is the transpose of
   ``sde_forward`` and counts as the forward.
 
@@ -22,6 +25,7 @@ An op belongs to the last scope in its path, as ``bench/trace_reduce.py``
 reads it: the transpose of the loss counts as the loss.
 """
 import re
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -42,6 +46,8 @@ _BODY = "jit(scanned)/while/body"
 _UNNAMEABLE = {
     "", f"{_BODY}/closed_call", f"{_BODY}/add",
     f"{_BODY}/dynamic_update_slice", f"{_BODY}/closed_call/iota",
+}
+_VMAP_BOUNDARY = {
     f"{_BODY}/closed_call/jvp(vmap())/mul",
     f"{_BODY}/closed_call/jvp(vmap())/transpose",
     f"{_BODY}/closed_call/transpose(jvp(vmap()))/transpose",
@@ -55,7 +61,18 @@ def _scope_of(path):
     return found[-1] if found else None
 
 
-def _compiled_hlo(adjoint, loss):
+def _compiled_hlo(route, loss):
+    """``route`` is an adjoint, or ``reversible-paths``: the reversible
+    batch solved as one, whatever its size."""
+    adjoint = route.split("-")[0]
+    with pytest.MonkeyPatch.context() as mp:
+        if route == "reversible-paths":
+            mp.setattr(sys.modules["repro.core.sdeint"],
+                       "_PER_PATH_CARRY_BYTES", 0)
+        return _compiled_step_hlo(adjoint, loss)
+
+
+def _compiled_step_hlo(adjoint, loss):
     target = jax.random.normal(jax.random.PRNGKey(1), (16, 3))
 
     def loss_of(p, r):
@@ -115,7 +132,8 @@ def _reachable(hlo, root):
 
 @pytest.fixture(scope="module", params=[
     ("reversible", "moment_mse"), ("reversible", "signature_mmd"),
-    ("full", "moment_mse"), ("full", "signature_mmd")],
+    ("full", "moment_mse"), ("full", "signature_mmd"),
+    ("reversible-paths", "moment_mse"), ("reversible-paths", "signature_mmd")],
     ids=lambda p: "-".join(p))
 def compiled(request):
     return request.param, _compiled_hlo(*request.param)
@@ -131,7 +149,7 @@ def test_every_scope_names_some_instruction(compiled):
 
 
 def test_scanned_body_ops_carry_a_scope(compiled):
-    _, hlo = compiled
+    (route, _), hlo = compiled
     entry = re.search(r"^ENTRY %(\S+) ", hlo, re.M).group(1)
     outer = [line for c, op, _, line in _instructions(hlo)
              if c == entry and op == "while"]
@@ -143,7 +161,9 @@ def test_scanned_body_ops_carry_a_scope(compiled):
               if c in inside and op in _RANKED]
     assert len(ranked) > 50
     unnamed = {p for op, p in ranked if _scope_of(p) is None}
-    assert unnamed <= _UNNAMEABLE, unnamed - _UNNAMEABLE
+    allowed = _UNNAMEABLE | (set() if route == "reversible-paths"
+                             else _VMAP_BOUNDARY)
+    assert unnamed <= allowed, unnamed - allowed
     # what no scope can name is a small part of the body
     n_unnamed = sum(_scope_of(p) is None for _, p in ranked)
     assert n_unnamed < 0.25 * len(ranked)
