@@ -44,25 +44,32 @@ def wrapped_energy_score(samples_th, samples_om, target_th, target_om):
     return term1 - term2
 
 
+def _outer(a, b):
+    """Per-segment outer product, (t, ...) x (t, m) -> (t, ..., m), as a
+    broadcast product rather than a ``dot``."""
+    return a[..., None] * jnp.expand_dims(b, tuple(range(1, a.ndim)))
+
+
 def _signature_l3(path):
     """Truncated signature (levels 1..3) of the piecewise-linear path (T, d).
 
     Level-k terms are iterated integrals; for a piecewise-linear path they
     reduce to iterated sums with the in-segment Chen corrections (1/2 at
-    level 2; 1/2, 1/2, 1/6 at level 3).
+    level 2; 1/2, 1/2, 1/6 at level 3).  The sums are reordered so that no
+    prefix sum is left (a ``cumsum`` is a ``reduce-window`` on TPU): the
+    increment before segment t is ``pre[t] = x_t - x_0``, and the level-2
+    term of segment u meets the increment after it, ``post[u] = x_{T-1} -
+    x_{u+1}``, so ``sum_t (sum_{u<t} seg2[u]) (x) dx[t] = sum_u seg2[u] (x)
+    post[u]``.  The products are broadcasts, not contractions: with no
+    ``dot`` in the loss, XLA keeps the batch of paths in the minor dimension.
     """
-    dx = jnp.diff(path, axis=0)  # (T-1, d)
-    s1 = jnp.sum(dx, axis=0)
-    pre = jnp.cumsum(dx, axis=0) - dx  # increment strictly before each segment
-    seg2 = jnp.einsum("ti,tj->tij", pre, dx) + 0.5 * jnp.einsum("ti,tj->tij", dx, dx)
-    s2 = jnp.sum(seg2, axis=0)
-    pre2 = jnp.cumsum(seg2, axis=0) - seg2  # level-2 signature before segment
-    s3 = (
-        jnp.einsum("tij,tk->ijk", pre2, dx)
-        + 0.5 * jnp.einsum("ti,tj,tk->ijk", pre, dx, dx)
-        + (1.0 / 6.0) * jnp.einsum("ti,tj,tk->ijk", dx, dx, dx)
-    )
-    return jnp.concatenate([s1.ravel(), s2.ravel(), s3.ravel()])
+    dx = path[1:] - path[:-1]  # (T-1, d)
+    pre = path[:-1] - path[0]  # increment strictly before each segment
+    post = path[-1] - path[1:]  # increment strictly after each segment
+    seg2 = _outer(pre + 0.5 * dx, dx)
+    seg3 = _outer(seg2, post) + _outer(_outer(0.5 * pre + dx / 6.0, dx), dx)
+    s1 = path[-1] - path[0]
+    return jnp.concatenate([s1.ravel(), seg2.sum(0).ravel(), seg3.sum(0).ravel()])
 
 
 def signature_mmd(gen_paths, target_paths, times=None):
