@@ -29,10 +29,15 @@ reproducible drivers make every ``dW[n]`` recomputable in O(1) memory during
 the backward sweep.  Step rejection happened at realization time, so the
 two-register reverse step needs no third (3S*) register.
 
-Saved trajectories come in two forms, identical bitwise across adjoints:
-``save_every`` (every k-th step, fixed grids) and ``save_at`` (dense output
-linearly interpolated onto an arbitrary time grid — any grid, with the
-cotangents of each save point injected along the reversible backward sweep).
+The forward sweep is written once (``_forward``): every adjoint runs the same
+scan of the same step body, and they differ only in what the backward pass
+keeps of it — all of it (full), its chunk boundaries (recursive), or only the
+final solver state (reversible).  So ``y_final``, the saved trajectory and the
+guard's ``diverged`` flag are bitwise identical across adjoints.  Saved
+trajectories come in two forms: ``save_every`` (every k-th step, fixed grids)
+and ``save_at`` (dense output linearly interpolated onto an arbitrary time
+grid — any grid, with the cotangents of each save point injected along the
+reversible backward sweep).
 """
 from __future__ import annotations
 
@@ -127,12 +132,7 @@ def _broadcast_saves(y0, n_saves: int):
     )
 
 
-def _pick_step(dWs, n):
-    """Step ``n``'s increment from the stacked bulk realization."""
-    return jax.tree_util.tree_map(lambda x: x[n], dWs)
-
-
-def _make_stepper(solver, term, grid: TimeGrid, args, masked, dWs=None):
+def _make_stepper(solver, term, grid: TimeGrid, args, masked, dWs):
     """One grid step ``((state, w), n) -> ((new_state, w_next), (t, h))``;
     zero-length padding steps of a realized grid are a no-op.
 
@@ -145,55 +145,46 @@ def _make_stepper(solver, term, grid: TimeGrid, args, masked, dWs=None):
     forward sweeps *stream* the path — ``w`` carries ``W(ts[n])`` so each
     step costs one tree descent instead of the two a fresh
     ``increment_over`` query pays — and otherwise each step queries
-    ``grid.increment(n)``.  All three spellings produce bitwise-identical
-    increments (``weval``/``fold_in`` are pure functions of their inputs).
-    Returns ``(init_w, step)``; ``init_w()`` builds the initial carry
-    element.
+    ``grid.increment(n)``.  All three increment sources produce
+    bitwise-identical increments (``weval``/``fold_in`` are pure functions
+    of their inputs).  Returns ``(init_w, step)``; ``init_w()`` builds the
+    initial carry element.
     """
     driver = grid.driver
-    stream = dWs is None and driver is not None and hasattr(driver, "weval")
     needs_levy = getattr(solver, "needs_levy_area", False)
+
+    def init_w():
+        return None
 
     if dWs is not None:
         # For Levy-area solvers the buffer is the stacked (dWs, dHs) pair
-        # (see TimeGrid.levy_increments); _pick_step indexes the pair pytree.
-        def init_w():
-            return None
-
-        def step(carry, n):
-            state, w = carry
-            t, h = grid.t_of(n), grid.h_of(n)
-            new = solver.step(term, state, t, h, _pick_step(dWs, n), args)
-            if masked:
-                new = tree_select(h > 0, new, state)
-            return (new, w), (t, h)
-    elif stream:
+        # (see TimeGrid.levy_increments); row n is taken from the pytree.
+        def increment(n, w):
+            return jax.tree_util.tree_map(lambda x: x[n], dWs), w
+    elif driver is not None and hasattr(driver, "weval"):
         def init_w():
             return driver.weval(grid.ts[0])
 
-        def step(carry, n):
-            state, w = carry
-            t, h = grid.t_of(n), grid.h_of(n)
+        def increment(n, w):
             w_next = driver.weval(grid.ts[n + 1])
             dW = jax.tree_util.tree_map(jnp.subtract, w_next, w)
             if needs_levy:
                 dW = (dW, driver.levy_area(grid.ts[n], grid.ts[n + 1]))
-            new = solver.step(term, state, t, h, dW, args)
-            if masked:
-                new = tree_select(h > 0, new, state)
-            return (new, w_next), (t, h)
+            return dW, w_next
     else:
-        def init_w():
-            return None
+        def increment(n, w):
+            if needs_levy:
+                return grid.levy_increment(n), w
+            return grid.increment(n), w
 
-        def step(carry, n):
-            state, w = carry
-            t, h = grid.t_of(n), grid.h_of(n)
-            dW = grid.levy_increment(n) if needs_levy else grid.increment(n)
-            new = solver.step(term, state, t, h, dW, args)
-            if masked:
-                new = tree_select(h > 0, new, state)
-            return (new, w), (t, h)
+    def step(carry, n):
+        state, w = carry
+        t, h = grid.t_of(n), grid.h_of(n)
+        dW, w = increment(n, w)
+        new = solver.step(term, state, t, h, dW, args)
+        if masked:
+            new = tree_select(h > 0, new, state)
+        return (new, w), (t, h)
 
     if getattr(grid, "is_padded", False):
         # Padded-uniform grids (bucketed dispatch): skip steps at or past
@@ -218,127 +209,102 @@ def _make_stepper(solver, term, grid: TimeGrid, args, masked, dWs=None):
     return init_w, step
 
 
-def _saving_step(solver, term, grid: TimeGrid, args, masked, save_ts,
-                 eps_end, h_floor, dWs=None):
-    """Scan body over ``((state, w), ys)`` carrying the dense-output buffer —
-    the ONE spelling of the step+fill invariant every adjoint's forward
-    pass shares (bitwise-identical ``ys`` across adjoints)."""
-    init_w, step = _make_stepper(solver, term, grid, args, masked, dWs)
-
-    def one(carry, n):
-        sw, ys = carry
-        new_sw, (t, h) = step(sw, n)
-        live = (h > 0) if masked else True
-        ys = fill_saves(ys, save_ts, live, t, grid.ts[n + 1],
-                        solver.extract(sw[0]), solver.extract(new_sw[0]),
-                        grid.t1, eps_end, h_floor)
-        return (new_sw, ys), None
-
-    return init_w, one
-
-
 # ---------------------------------------------------------------------------
-# Full & recursive adjoints: scan-of-scans, optionally rematerialised.
+# The forward sweep of all three adjoints (full & recursive differentiate it).
 # ---------------------------------------------------------------------------
+
+def _scan_steps(body, carry, n0, length, remat_chunk):
+    """Scan ``body`` over the ``length`` step indices from ``n0`` (from 0
+    when ``n0`` is None), in ``jax.checkpoint``-ed chunks of
+    ``remat_chunk`` steps when that is set."""
+
+    def from_n0(idx):
+        return idx if n0 is None else n0 + idx
+
+    if remat_chunk is None:
+        carry, _ = jax.lax.scan(body, carry, from_n0(jnp.arange(length)))
+        return carry
+    if length % remat_chunk != 0:
+        raise ValueError(f"{length} steps are not divisible by "
+                         f"remat_chunk={remat_chunk}")
+
+    @jax.checkpoint
+    def chunk(carry, c0):
+        carry, _ = jax.lax.scan(body, carry, c0 + jnp.arange(remat_chunk))
+        return carry, None
+
+    carry, _ = jax.lax.scan(
+        chunk, carry, from_n0(remat_chunk * jnp.arange(length // remat_chunk)))
+    return carry
+
 
 @jax.named_scope("sde_forward")
-def _solve_scan(solver, term, y0, grid: TimeGrid, args, save_every, remat_chunk,
-                save_at=None, dWs=None, guard=None):
+def _forward(solver, term, y0, grid: TimeGrid, args, save_every, save_at,
+             dWs, guard, remat_chunk=None):
+    """THE forward sweep of every adjoint: ``(state_f, ys, diverged)``, with
+    ``state_f`` the solver state before ``extract`` (the reversible backward
+    starts from it).  One spelling is what keeps ``ys`` bitwise-identical
+    across adjoints.
+
+    The guard only *observes*: the step scan is the exact unguarded program
+    (guarded results stay bitwise-identical), and it reduces at save-segment
+    boundaries, or after the loop over the final state and the save buffer
+    under ``save_at``.  Non-finites persist once they enter the state and a
+    genuine blow-up stays above any threshold, so those checks detect every
+    blow-up a per-step check would, at ~1/seg_len the overhead.
+    """
     masked = not grid.is_uniform
-    guarded = guard is not None
+    init_w, step = _make_stepper(solver, term, grid, args, masked, dWs)
+    state0 = solver.init(term, grid.t0, y0, args)
 
     if save_at is not None:
         # Dense output on an arbitrary time grid: one flat scan carrying the
         # save buffer, filled by whichever step covers each save time.
         save_ts, eps_end, h_floor = _save_consts(grid, save_at)
-        init_w, one = _saving_step(solver, term, grid, args, masked, save_ts,
-                                   eps_end, h_floor, dWs)
-        carry0 = ((solver.init(term, grid.t0, y0, args), init_w()),
-                  _broadcast_saves(y0, len(save_at)))
 
-        if remat_chunk is not None:
-            if grid.n_steps % remat_chunk != 0:
-                raise ValueError("n_steps must be divisible by remat_chunk")
+        def one(carry, n):
+            sw, ys = carry
+            new_sw, (t, h) = step(sw, n)
+            live = (h > 0) if masked else True
+            ys = fill_saves(ys, save_ts, live, t, grid.ts[n + 1],
+                            solver.extract(sw[0]), solver.extract(new_sw[0]),
+                            grid.t1, eps_end, h_floor)
+            return (new_sw, ys), None
 
-            @jax.checkpoint
-            def chunk(carry, c0):
-                carry, _ = jax.lax.scan(one, carry, c0 + jnp.arange(remat_chunk))
-                return carry, None
-
-            starts = remat_chunk * jnp.arange(grid.n_steps // remat_chunk)
-            final, _ = jax.lax.scan(chunk, carry0, starts)
-        else:
-            final, _ = jax.lax.scan(one, carry0, jnp.arange(grid.n_steps))
-        ((state_f, _), ys) = final
+        carry0 = ((state0, init_w()), _broadcast_saves(y0, len(save_at)))
+        (state_f, _), ys = _scan_steps(one, carry0, None, grid.n_steps,
+                                       remat_chunk)
         div = None
-        if guarded:
-            # The guard only *observes* the outputs — the scan itself is the
-            # exact unguarded program, so guarded results stay
-            # bitwise-identical.  Non-finites persist once they enter the
-            # state, so checking the final state + save buffer outside the
-            # loop detects every blow-up the per-step check would, at zero
-            # in-loop cost.
+        if guard is not None:
             div = (tree_blowup(solver.extract(state_f), guard)
                    | tree_blowup(ys, guard))
-        return SolveResult(solver.extract(state_f), ys, div)
+        return state_f, ys, div
 
     n_seg, seg_len = _segment_counts(grid.n_steps, save_every)
-    init_w, step = _make_stepper(solver, term, grid, args, masked, dWs)
 
     def one_step(carry, n):
         return step(carry, n)[0], None
 
-    if remat_chunk is None:
-        def run_segment(sw, n0):
-            sw, _ = jax.lax.scan(one_step, sw, n0 + jnp.arange(seg_len))
-            return sw
-    else:
-        if seg_len % remat_chunk != 0:
-            raise ValueError("segment length must be divisible by remat_chunk")
-
-        @jax.checkpoint
-        def chunk(carry, c0):
-            carry, _ = jax.lax.scan(one_step, carry, c0 + jnp.arange(remat_chunk))
-            return carry, None
-
-        def run_segment(sw, n0):
-            sw, _ = jax.lax.scan(
-                chunk, sw, n0 + remat_chunk * jnp.arange(seg_len // remat_chunk)
-            )
-            return sw
-
-    if guarded:
-        # Guard reduces at save-segment boundaries, not every step: the inner
-        # step scan is the exact unguarded program (guarded results stay
-        # bitwise-identical) and a blown-up state cannot recover to a clean
-        # one across a segment (non-finites persist; a genuine blow-up stays
-        # above any threshold), so boundary checks detect everything the
-        # per-step check would at ~1/seg_len the overhead.
-        def segment(carry, n0):
-            sw, div = carry
-            sw = run_segment(sw, n0)
+    def segment(carry, n0):
+        sw, div = carry
+        sw = _scan_steps(one_step, sw, n0, seg_len, remat_chunk)
+        if guard is not None:
             div = div | tree_blowup(solver.extract(sw[0]), guard)
-            return (sw, div), (solver.extract(sw[0]) if save_every else None)
+        return (sw, div), (solver.extract(sw[0]) if save_every else None)
 
-        def state_of(carry):
-            return carry[0][0]
+    div0 = None if guard is None else jnp.asarray(False)
+    ((state_f, _), div), ys = jax.lax.scan(
+        segment, ((state0, init_w()), div0), seg_len * jnp.arange(n_seg))
+    return state_f, (ys if save_every else None), div
 
-        carry0 = ((solver.init(term, grid.t0, y0, args), init_w()),
-                  jnp.asarray(False))
-    else:
-        def segment(carry, n0):
-            sw = run_segment(carry, n0)
-            return sw, (solver.extract(sw[0]) if save_every else None)
 
-        def state_of(carry):
-            return carry[0]
-
-        carry0 = (solver.init(term, grid.t0, y0, args), init_w())
-    starts = seg_len * jnp.arange(n_seg)
-    final, ys = jax.lax.scan(segment, carry0, starts)
-    div = final[1] if guarded else None
-    return SolveResult(solver.extract(state_of(final)),
-                       ys if save_every else None, div)
+def _solve_scan(solver, term, y0, grid: TimeGrid, args, save_every, remat_chunk,
+                save_at, dWs, guard):
+    """Full adjoint (plain autodiff through the forward scan), or recursive
+    with ``remat_chunk``: its chunks are rematerialised on the backward."""
+    state_f, ys, div = _forward(solver, term, y0, grid, args, save_every,
+                                save_at, dWs, guard, remat_chunk)
+    return SolveResult(solver.extract(state_f), ys, div)
 
 
 # ---------------------------------------------------------------------------
@@ -360,7 +326,6 @@ def _solve_reversible(solver, term, y0, grid: TimeGrid, args, save_every,
     n_steps = grid.n_steps
     n_seg, seg_len = _segment_counts(n_steps, save_every)
     masked = not grid.is_uniform
-    guarded = guard is not None
     needs_levy = getattr(solver, "needs_levy_area", False)
     if save_at is not None:
         save_ts, eps_end, h_floor = _save_consts(grid, save_at)
@@ -379,71 +344,22 @@ def _solve_reversible(solver, term, y0, grid: TimeGrid, args, save_every,
     def extract_vjp(s, c):
         return jax.vjp(solver.extract, s)[1](c)[0]
 
-    @jax.named_scope("sde_forward")
     def forward(grid, y0, args, dWs):
-        state0 = solver.init(term, grid.t0, y0, args)
-
-        if save_at is not None:
-            init_w, one = _saving_step(solver, term, grid, args, masked,
-                                       save_ts, eps_end, h_floor, dWs)
-            carry0 = ((state0, init_w()), _broadcast_saves(y0, len(save_at)))
-            final, _ = jax.lax.scan(one, carry0, jnp.arange(n_steps))
-            ((state_f, _), ys) = final
-            div = None
-            if guarded:
-                # Observer-only, post-loop (see _solve_scan): non-finites
-                # persist, so final state + save buffer see every blow-up.
-                div = (tree_blowup(solver.extract(state_f), guard)
-                       | tree_blowup(ys, guard))
-            return state_f, ys, div
-
-        init_w, step = _make_stepper(solver, term, grid, args, masked, dWs)
-
-        def one_step(carry, n):
-            return step(carry, n)[0], None
-
-        if guarded:
-            # Save-segment-boundary guard, exactly as in _solve_scan: the
-            # inner step scan is the unguarded program (bitwise-identical
-            # results), divergence is reduced once per segment.
-            def segment(carry, n0):
-                sw, div = carry
-                sw, _ = jax.lax.scan(one_step, sw, n0 + jnp.arange(seg_len))
-                div = div | tree_blowup(solver.extract(sw[0]), guard)
-                return (sw, div), (solver.extract(sw[0]) if save_every
-                                   else None)
-
-            def state_of(carry):
-                return carry[0][0]
-
-            carry0 = ((state0, init_w()), jnp.asarray(False))
-        else:
-            def segment(carry, n0):
-                carry, _ = jax.lax.scan(one_step, carry,
-                                        n0 + jnp.arange(seg_len))
-                return carry, (solver.extract(carry[0]) if save_every
-                               else None)
-
-            def state_of(carry):
-                return carry[0]
-
-            carry0 = (state0, init_w())
-        final, ys = jax.lax.scan(segment, carry0, seg_len * jnp.arange(n_seg))
-        div = final[1] if guarded else None
-        return state_of(final), (ys if save_every else None), div
+        # save_ts, already converted: no conversion inside the custom vjp
+        return _forward(solver, term, y0, grid, args, save_every,
+                        None if save_at is None else save_ts, dWs, guard)
 
     if paths:
         forward = jax.vmap(forward, in_axes=(None, None, None, 0))
-
-    @jax.custom_vjp
-    def run(grid, y0, args, dWs):
-        state_f, ys, div = forward(grid, y0, args, dWs)
-        return SolveResult(lift(solver.extract)(state_f), ys, div)
 
     def run_fwd(grid, y0, args, dWs):
         state_f, ys, div = forward(grid, y0, args, dWs)
         return SolveResult(lift(solver.extract)(state_f), ys, div), (
             grid, state_f, args, dWs)
+
+    @jax.custom_vjp
+    def run(grid, y0, args, dWs):
+        return run_fwd(grid, y0, args, dWs)[0]
 
     @jax.named_scope("sde_reverse")
     def run_bwd(res, ct):
@@ -631,8 +547,9 @@ def solve(
 
     Every integration in the repo bottoms out here: fixed uniform grids,
     matched-driver grids over a Virtual Brownian Tree, and adaptively
-    realized (non-uniform) grids all run the same scan, under the same three
-    adjoints.
+    realized (non-uniform) grids all run the same forward scan, under the
+    same three adjoints — one forward sweep for all three, which differ
+    only in their backward pass.
 
     Parameters
     ----------
@@ -742,15 +659,12 @@ def solve(
     else:
         dWs = None
     term, dWs = _maybe_prediffuse(solver, term, y0, grid, args, adjoint, dWs)
-    if adjoint == "full":
-        return _solve_scan(solver, term, y0, grid, args, save_every, None,
-                           save_at, dWs, guard)
-    if adjoint == "recursive":
-        if remat_chunk is None:
-            seg = save_every if save_every is not None else grid.n_steps
-            remat_chunk = max(1, int(math.isqrt(seg)))
-            while seg % remat_chunk != 0:
-                remat_chunk -= 1
+    if adjoint == "recursive" and remat_chunk is None:
+        seg = save_every if save_every is not None else grid.n_steps
+        remat_chunk = max(1, int(math.isqrt(seg)))
+        while seg % remat_chunk != 0:
+            remat_chunk -= 1
+    if adjoint in ("full", "recursive"):
         return _solve_scan(solver, term, y0, grid, args, save_every,
                            remat_chunk, save_at, dWs, guard)
     if adjoint == "reversible":

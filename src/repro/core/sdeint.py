@@ -299,10 +299,8 @@ def _reversible_paths_fn(term, solver, t0, t1, n_steps, y0, *, args,
     """``keys -> result`` of a fixed-grid reversible batch solved as one
     (:func:`~repro.core.adjoint.solve_reversible_paths`); bitwise the same
     results as the vmapped single-trajectory fn."""
-    if noise_shape is None:
-        noise_shape = _infer_noise_shape(term, y0)
-    if dtype is None:
-        dtype = _infer_dtype(y0)
+    solver, _, noise_shape, dtype = _resolve(term, solver, y0, "reversible",
+                                             noise_shape, dtype)
     needs_levy = getattr(solver, "needs_levy_area", False)
 
     def increments(k):
@@ -390,30 +388,58 @@ def sdeint_ticks(
                 f"int array (one live-step count per tick), got shape "
                 f"{tuple(active.shape)}"
             )
-        one = _padded_trajectory_fn(term, solver, t0, n_steps, y0,
-                                    float(step_size), **kwargs)
-        batched = _batched_fn(jax.vmap(one, in_axes=(0, None)),
-                              leaf.shape[1], mesh, mesh_axis, n_operands=2)
-        if leaf.shape[0] == 1:
-            out = batched(jax.tree_util.tree_map(lambda k: k[0], tick_keys),
-                          active[0])
-            return jax.tree_util.tree_map(lambda x: x[None], out)
-        return jax.lax.map(lambda kn: batched(kn[0], kn[1]),
-                           (tick_keys, active))
-    if step_size is not None:
-        raise ValueError("step_size only applies with active_steps (padded "
-                         "bucketed dispatch)")
-
-    one = _trajectory_fn(term, solver, t0, t1, n_steps, y0, **kwargs)
-    batched = _batched_fn(jax.vmap(one), leaf.shape[1], mesh, mesh_axis)
+        one = jax.vmap(_padded_trajectory_fn(term, solver, t0, n_steps, y0,
+                                             float(step_size), **kwargs),
+                       in_axes=(0, None))
+        operands = (tick_keys, active)
+    else:
+        if step_size is not None:
+            raise ValueError("step_size only applies with active_steps "
+                             "(padded bucketed dispatch)")
+        one = jax.vmap(_trajectory_fn(term, solver, t0, t1, n_steps, y0,
+                                      **kwargs))
+        operands = (tick_keys,)
+    batched = _batched_fn(one, leaf.shape[1], mesh, mesh_axis,
+                          n_operands=len(operands))
     if leaf.shape[0] == 1:
         # Serving-tail fast path: a depth-1 stack needs no on-device tick
         # loop — run the single batch directly and restore the tick axis.
         # Bitwise-identical: the lax.map body below is this same batched fn,
         # and per-tick bits are key-determined (regression-tested).
-        out = batched(jax.tree_util.tree_map(lambda k: k[0], tick_keys))
+        out = batched(*jax.tree_util.tree_map(lambda x: x[0], operands))
         return jax.tree_util.tree_map(lambda x: x[None], out)
-    return jax.lax.map(batched, tick_keys)
+    return jax.lax.map(lambda ops: batched(*ops), operands)
+
+
+def _resolve(term, solver, y0, adjoint, noise_shape, dtype, *,
+             adaptive=False, rtol=None, atol=None, h0=None, bm_tol=None,
+             bounded=True, why=""):
+    """The checks and defaults every single-trajectory builder shares.
+
+    Resolves ``solver``, rejects an unknown adjoint and, on a fixed grid, any
+    adaptive-only option that is set (``why`` ends that message), and fills
+    in ``noise_shape`` and ``dtype`` from the term and ``y0``.  Returns
+    ``(solver, adaptive, noise_shape, dtype)``, with ``adaptive`` true also
+    for an ``:adaptive`` solver spec.
+    """
+    solver = get_solver(solver)
+    adaptive = adaptive or getattr(solver, "adaptive", False)
+    if adjoint not in ("full", "recursive", "reversible"):
+        raise ValueError(f"unknown adjoint {adjoint!r}")
+    if not adaptive:
+        for opt_name, bad in (("rtol", rtol is not None),
+                              ("atol", atol is not None),
+                              ("h0", h0 is not None),
+                              ("bm_tol", bm_tol is not None),
+                              ("bounded", bounded is not True)):
+            if bad:
+                raise ValueError(
+                    f"{opt_name} only applies to adaptive solves{why}")
+    if noise_shape is None:
+        noise_shape = _infer_noise_shape(term, y0)
+    if dtype is None:
+        dtype = _infer_dtype(y0)
+    return solver, adaptive, noise_shape, dtype
 
 
 def _trajectory_fn(
@@ -424,10 +450,11 @@ def _trajectory_fn(
 ):
     """Validate options and build the single-trajectory ``key -> result`` fn
     (shared by :func:`sdeint` and :func:`sdeint_ticks`)."""
-    solver = get_solver(solver)
-    adaptive = adaptive or getattr(solver, "adaptive", False)
-    if adjoint not in ("full", "recursive", "reversible"):
-        raise ValueError(f"unknown adjoint {adjoint!r}")
+    solver, adaptive, noise_shape, dtype = _resolve(
+        term, solver, y0, adjoint, noise_shape, dtype, adaptive=adaptive,
+        rtol=rtol, atol=atol, h0=h0, bm_tol=bm_tol, bounded=bounded,
+        why="; pass adaptive=True or an ':adaptive' solver spec — a "
+            "tolerance request must not silently run a fixed grid")
     if adaptive and not bounded and adjoint != "full":
         raise ValueError(
             f"bounded=False (single controller pass) is forward-only and "
@@ -444,22 +471,6 @@ def _trajectory_fn(
             "save_at (arbitrary-time dense output) requires adaptive=True / "
             "an ':adaptive' solver spec; on a fixed grid use save_every"
         )
-    if not adaptive:
-        for opt_name, bad in (("rtol", rtol is not None),
-                              ("atol", atol is not None),
-                              ("h0", h0 is not None),
-                              ("bm_tol", bm_tol is not None),
-                              ("bounded", bounded is not True)):
-            if bad:
-                raise ValueError(
-                    f"{opt_name} only applies to adaptive solves; pass "
-                    "adaptive=True or an ':adaptive' solver spec — a "
-                    "tolerance request must not silently run a fixed grid"
-                )
-    if noise_shape is None:
-        noise_shape = _infer_noise_shape(term, y0)
-    if dtype is None:
-        dtype = _infer_dtype(y0)
 
     if adaptive:
         tols = {}
@@ -501,8 +512,11 @@ def _padded_trajectory_fn(
     for bucketed dispatch: ``h`` is the bucket's exact static step size,
     ``n_padded`` its ladder rung, ``n_active`` the (traced, batch-uniform)
     true step count of one tick."""
-    solver = get_solver(solver)
-    if adaptive or getattr(solver, "adaptive", False):
+    solver, adaptive, noise_shape, dtype = _resolve(
+        term, solver, y0, adjoint, noise_shape, dtype, adaptive=adaptive,
+        rtol=rtol, atol=atol, h0=h0, bm_tol=bm_tol, bounded=bounded,
+        why=", which cannot run under padded bucketed dispatch")
+    if adaptive:
         raise ValueError(
             "active_steps (padded bucketed dispatch) applies to fixed-grid "
             "solves only; adaptive requests must dispatch exact"
@@ -512,22 +526,6 @@ def _padded_trajectory_fn(
             "padded bucketed dispatch carries no saved trajectories; "
             "save_every/save_at requests must dispatch exact"
         )
-    for opt_name, bad in (("rtol", rtol is not None),
-                          ("atol", atol is not None),
-                          ("h0", h0 is not None),
-                          ("bm_tol", bm_tol is not None),
-                          ("bounded", bounded is not True)):
-        if bad:
-            raise ValueError(
-                f"{opt_name} only applies to adaptive solves, which cannot "
-                "run under padded bucketed dispatch"
-            )
-    if adjoint not in ("full", "recursive", "reversible"):
-        raise ValueError(f"unknown adjoint {adjoint!r}")
-    if noise_shape is None:
-        noise_shape = _infer_noise_shape(term, y0)
-    if dtype is None:
-        dtype = _infer_dtype(y0)
 
     def one(k, n_active):
         bm = padded_brownian_path(k, t0, h, n_padded, shape=noise_shape,

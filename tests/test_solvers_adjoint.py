@@ -20,8 +20,10 @@ from repro.core import (
     euler,
     heun,
     midpoint,
+    realize_grid,
     rk4,
     solve,
+    virtual_brownian_tree,
 )
 
 KEY = jax.random.PRNGKey(0)
@@ -176,16 +178,39 @@ class TestAdjoints:
         gr = jax.grad(lambda y: loss(y, "reversible"))(y0)
         np.testing.assert_allclose(gf, gr, rtol=1e-6)
 
-    def test_saved_trajectory_identical_across_adjoints(self):
+    @pytest.mark.parametrize("bulk", [True, False], ids=["bulk", "per-step"])
+    @pytest.mark.parametrize("guard", [None, 1e6], ids=["unguarded", "guarded"])
+    @pytest.mark.parametrize("saves", ["save_every", "save_at"])
+    def test_saved_trajectory_identical_across_adjoints(self, saves, guard,
+                                                        bulk):
+        """Every adjoint runs the one forward sweep: ``y_final``, ``ys`` and
+        ``diverged`` are bitwise equal across full, recursive and
+        reversible, on a uniform grid with ``save_every`` and on an
+        adaptively realized grid with ``save_at``."""
         term = nonlinear_sde_term()
-        bm = brownian_path(KEY, 0.0, 1.0, 64, shape=(4,), dtype=jnp.float64)
         y0 = jnp.ones(4)
+        if saves == "save_every":
+            grid = brownian_path(KEY, 0.0, 1.0, 64, shape=(4,),
+                                 dtype=jnp.float64)
+            kw = {"save_every": 8}
+        else:
+            vbt = virtual_brownian_tree(KEY, 0.0, 1.0, shape=(4,),
+                                        dtype=jnp.float64)
+            grid = realize_grid("ees25", term, y0, vbt, ARGS, rtol=1e-3,
+                                max_steps=64).grid
+            kw = {"save_at": jnp.linspace(0.0, 1.0, 9)}
         outs = [
-            solve(ees25_solver(), term, y0, bm, ARGS, adjoint=a, save_every=8).ys
+            solve(ees25_solver(), term, y0, grid, ARGS, adjoint=a,
+                  guard=guard, bulk_increments=bulk, **kw)
             for a in ("full", "recursive", "reversible")
         ]
-        np.testing.assert_allclose(outs[0], outs[1], atol=0)
-        np.testing.assert_allclose(outs[0], outs[2], atol=0)
+        for out in outs[1:]:
+            np.testing.assert_array_equal(out.y_final, outs[0].y_final)
+            np.testing.assert_array_equal(out.ys, outs[0].ys)
+            if guard is None:
+                assert out.diverged is None
+            else:
+                np.testing.assert_array_equal(out.diverged, outs[0].diverged)
 
 
 class TestBrownian:
